@@ -289,6 +289,12 @@ def _report_path() -> str:
 
 
 def main() -> list[Row]:
+    from repro.resilience.watchdog import holds_accelerator
+    if holds_accelerator():
+        # the legs kill and restart worker and trainer processes, which
+        # could never reach a chip this process already holds
+        raise RuntimeError("chaos_smoke drives child processes and runs "
+                           "on the CPU host only (JAX_PLATFORMS=cpu)")
     rows: list[Row] = []
     report: dict = {}
     _run_sweep_leg(rows, report)

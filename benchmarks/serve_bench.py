@@ -42,7 +42,7 @@ def main() -> list[Row]:
         res = s.serve(CONFIG, n_requests=N_REQUESTS, trace="poisson",
                       rate=1.0, seed=0, n_slots=2, max_len=32,
                       prefill_chunk=8, page_size=8)
-        rec, stats = res.data
+        rec, stats, _engine = res.data
         summ = stats.summary()
 
         # latency gate: Session.serve folds it into the exit code

@@ -59,6 +59,7 @@ import os
 import sys
 from typing import Sequence
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import FUSION_MODES
 from repro.session.workspace import WORKSPACE_ENV, Workspace
 
@@ -87,9 +88,16 @@ def _workspace_env(root: str):
 
 
 def _session(args):
+    """Commands with a ``--machine`` flag default to the model of this
+    process's device.  The read-only ones (trend, advise, merge) take the
+    machine the workspace was recorded against and never ask JAX for a
+    device: on an accelerator that would claim the chip from the process
+    measuring on it."""
     from repro.session import Session
-    return Session(machine=getattr(args, "machine", "cpu-host"),
-                   workspace=Workspace(args.workspace))
+    ws = Workspace(args.workspace)
+    machine = (args.machine if hasattr(args, "machine")
+               else ws.read_header().get("machine", "cpu-host"))
+    return Session(machine=machine, workspace=ws)
 
 
 # --------------------------------------------------------------------------
@@ -97,6 +105,7 @@ def _session(args):
 # --------------------------------------------------------------------------
 
 def cmd_characterize(args) -> int:
+    enable_compile_cache()
     s = _session(args)
     res = s.characterize(empirical=args.empirical, tuned=not args.untuned,
                          smoke=args.smoke)
@@ -107,6 +116,7 @@ def cmd_characterize(args) -> int:
 
 
 def cmd_profile(args) -> int:
+    enable_compile_cache()
     s = _session(args)
     from repro.session.session import TRAIN_PHASES
     try:
@@ -126,6 +136,7 @@ def cmd_profile(args) -> int:
 
 
 def cmd_serve(args) -> int:
+    enable_compile_cache()
     s = _session(args)
     try:
         res = s.serve(args.config, n_requests=args.requests,
@@ -275,9 +286,10 @@ def build_parser() -> argparse.ArgumentParser:
                         help="machine model: datasheet or measured ERT "
                              "ceilings (paper §II-A)")
     _add_workspace(ch)
-    ch.add_argument("--machine", default="cpu-host",
+    ch.add_argument("--machine", default=None,
                     choices=sorted(MACHINES),
-                    help="machine model to start from (default cpu-host)")
+                    help="machine model to start from (default: the "
+                         "model of this process's device)")
     ch.add_argument("--empirical", action="store_true",
                     help="measure this host's ceilings (ERT micro-kernels) "
                          "instead of the datasheet numbers")
@@ -295,9 +307,10 @@ def build_parser() -> argparse.ArgumentParser:
     _add_workspace(pr)
     pr.add_argument("--config", required=True,
                     help="registry config name (see repro.configs)")
-    pr.add_argument("--machine", default="cpu-host",
+    pr.add_argument("--machine", default=None,
                     choices=sorted(MACHINES),
-                    help="machine model the bounds are against")
+                    help="machine model the bounds are against (default: "
+                         "the model of this process's device)")
     pr.add_argument("--phase", action="append",
                     choices=("fwd", "bwd", "opt"),
                     help="phase to profile (repeatable; default all three)")
@@ -336,9 +349,10 @@ def build_parser() -> argparse.ArgumentParser:
     _add_workspace(sv)
     sv.add_argument("--config", required=True,
                     help="registry config name (dense/moe families)")
-    sv.add_argument("--machine", default="cpu-host",
+    sv.add_argument("--machine", default=None,
                     choices=sorted(MACHINES),
-                    help="machine model the bounds are against")
+                    help="machine model the bounds are against (default: "
+                         "the model of this process's device)")
     sv.add_argument("--requests", type=int, default=16,
                     help="arrival-trace length (default 16)")
     sv.add_argument("--trace", default="poisson",

@@ -305,18 +305,70 @@ def _dot_flops(op: HloOp, comp: HloComputation) -> float:
     return 2.0 * out_elems * contract
 
 
+def _valid_taps(in_size: int, out_size: int, window: int, stride: int,
+                pad_lo: int, lhs_dilate: int, rhs_dilate: int) -> int:
+    """(output position, window tap) pairs of one spatial dim whose tap
+    lands on an input element rather than on padding or a dilation hole.
+    """
+    pos = (np.arange(out_size)[:, None] * stride
+           + np.arange(window)[None, :] * rhs_dilate - pad_lo)
+    ok = ((pos >= 0) & (pos <= (in_size - 1) * lhs_dilate)
+          & (pos % lhs_dilate == 0))
+    return int(ok.sum())
+
+
+def _window(attrs: str) -> dict[str, list] | None:
+    m = re.search(r"window=\{([^}]*)\}", attrs)
+    if not m:
+        return None
+    fields = dict(kv.split("=", 1) for kv in m.group(1).split())
+    size = [int(x) for x in fields["size"].split("x")]
+
+    def ints(key: str) -> list[int]:
+        return ([int(x) for x in fields[key].split("x")] if key in fields
+                else [1] * len(size))
+    pad = ([[int(v) for v in p.split("_")] for p in fields["pad"].split("x")]
+           if "pad" in fields else [[0, 0]] * len(size))
+    return {"size": size, "stride": ints("stride"), "pad": pad,
+            "lhs_dilate": ints("lhs_dilate"),
+            "rhs_dilate": ints("rhs_dilate")}
+
+
 def _conv_flops(op: HloOp, comp: HloComputation) -> float:
+    """2 x output batch x output features x input features per group x
+    the window taps that read input, summed over output positions.
+
+    Taps that land on padding are not counted: the TPU compiler lowers
+    some dots to convolutions whose window is mostly padding (a window of
+    384 over a one-element input), where counting every tap would inflate
+    the FLOPs by the window size.
+    """
     out_elems = op.shapes[0].elements
+    lhs = comp.ops.get(op.operands[0]) if op.operands else None
     rhs = comp.ops.get(op.operands[1]) if len(op.operands) > 1 else None
     if not (rhs and rhs.shapes):
         return 2.0 * out_elems
-    m = re.search(r"dim_labels=[^-]*_[^-]*->([a-z0-9]+)", op.attrs)
+    m = re.search(r"dim_labels=([a-z0-9]+)_([a-z0-9]+)->([a-z0-9]+)",
+                  op.attrs)
+    win = _window(op.attrs)
+    out_dims = op.shapes[0].dims
+    if m and win and lhs and lhs.shapes:
+        lhs_l, rhs_l, out_l = m.groups()
+        taps, out_spatial = 1, 1
+        for d in range(len(win["size"])):
+            o = out_dims[out_l.index(str(d))]
+            out_spatial *= o
+            taps *= _valid_taps(
+                lhs.shapes[0].dims[lhs_l.index(str(d))], o, win["size"][d],
+                win["stride"][d], win["pad"][d][0], win["lhs_dilate"][d],
+                win["rhs_dilate"][d])
+        cin = rhs.shapes[0].dims[rhs_l.index("i")]
+        return 2.0 * (out_elems / max(out_spatial, 1)) * cin * taps
     cout = 1
     if m:
-        out_labels = m.group(1)
-        fpos = out_labels.find("f")
-        if 0 <= fpos < len(op.shapes[0].dims):
-            cout = op.shapes[0].dims[fpos]
+        fpos = m.group(3).find("f")
+        if 0 <= fpos < len(out_dims):
+            cout = out_dims[fpos]
     return 2.0 * out_elems * rhs.shapes[0].elements / max(cout, 1)
 
 

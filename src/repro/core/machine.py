@@ -199,6 +199,32 @@ def get_machine(name: str = "tpu-v5e") -> MachineSpec:
     return MACHINES[name]
 
 
+#: ``device_kind`` as JAX reports it -> machine model (the CPU backend
+#: reports its kind as "cpu")
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+    "cpu": "cpu-host",
+}
+
+
+def machine_for_device(device=None) -> MachineSpec:
+    """Machine model of ``device`` (default: this process's first device).
+
+    Keyed on ``device_kind``; a device with no model is an error, never
+    a guessed peak.  Asking JAX for its devices initialises the backend,
+    and on an accelerator that claims the chip for this process.
+    """
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = device.device_kind
+    try:
+        return MACHINES[DEVICE_KINDS[kind]]
+    except KeyError:
+        raise KeyError(f"no machine model for device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None
+
+
 def empirical_cpu_spec(tuned: bool = True, store=None, smoke: bool = False,
                        backend: str = "xla") -> MachineSpec:
     """Measured machine model of *this* host (the real ERT loop).

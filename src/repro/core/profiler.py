@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import hlo_analysis
-from repro.core.compat import mesh_context
 from repro.core.hlo_analysis import ModuleAnalysis
 from repro.core.machine import MachineSpec, get_machine
 from repro.core.roofline import RooflineTerms, roofline_terms
@@ -44,6 +43,9 @@ class ProfileResult:
     n_devices: int
     wall_s: float | None = None      # measured median step time, if executed
     measure_iters: int = 0           # timed iterations behind wall_s
+    # cost_analysis counts transcendentals apart from flops; the walker
+    # counts each as one FLOP
+    xla_transcendentals: float = 0.0
 
     @property
     def peak_device_bytes(self) -> int:
@@ -93,6 +95,7 @@ def profile_compiled(name: str, compiled, machine: MachineSpec,
         xla_bytes=float(ca.get("bytes accessed", 0.0)),
         memory_stats=mem,
         n_devices=n_devices,
+        xla_transcendentals=float(ca.get("transcendentals", 0.0)),
     )
 
 
@@ -122,7 +125,7 @@ def compile_fn(fn: Callable, *, args: Sequence[Any],
         kwargs["static_argnums"] = static_argnums
     jitted = jax.jit(fn, **kwargs)
     if mesh is not None:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             return jitted.lower(*args).compile()
     return jitted.lower(*args).compile()
 

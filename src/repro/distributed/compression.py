@@ -25,7 +25,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import axis_size
 
 
 class ErrorFeedback(NamedTuple):
@@ -66,7 +65,7 @@ def psum_compressed(g: jax.Array, residual: jax.Array, axis_name: str
     Wire bytes: all_gather of int8 = (n-1)/n x N bytes vs fp32 ring
     all-reduce 2(n-1)/n x 4N — an 8x reduction.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     q, scale, new_residual = compress_with_feedback(g, residual)
     qs = jax.lax.all_gather(q, axis_name)            # (n, ...), int8 on wire
     scales = jax.lax.all_gather(scale, axis_name)    # (n,), negligible

@@ -21,19 +21,11 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import axis_size
-
 
 def _pvary(x: jax.Array, axis: str) -> jax.Array:
     """Mark an unvarying value as device-varying over a manual mesh axis
     (scan carries inside shard_map must have matching varying types)."""
-    f = getattr(jax.lax, "pvary", None)
-    if f is not None:
-        return f(x, (axis,))
-    f = getattr(jax.lax, "pcast", None)
-    if f is not None:                          # pragma: no cover
-        return f(x, (axis,), to="varying")
-    return x                                   # pragma: no cover
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -45,7 +37,7 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
     x_mbs: (M, b, s, d) microbatched hidden states (valid on stage 0).
     Returns (M, b, s, d) stage-S-1 outputs (valid on the last stage).
     """
-    S = axis_size(axis)
+    S = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     M = x_mbs.shape[0]
     ticks = M + S - 1
@@ -78,7 +70,7 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
 
 def stage_slice(n_layers: int, axis: str = "pod") -> tuple[jax.Array, int]:
     """(my first layer index, layers per stage) inside shard_map."""
-    S = axis_size(axis)
+    S = jax.lax.axis_size(axis)
     per = n_layers // S
     return jax.lax.axis_index(axis) * per, per
 

@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import RunConfig
-from repro.core.compat import ambient_mesh
 from repro.models.params import P, tree_map_specs
 
 # logical axis → mesh axis under TP/EP
@@ -284,7 +283,7 @@ def constrain(x: jax.Array, run: RunConfig, *logical: str | None) -> jax.Array:
     No-op when there is no ambient mesh (plain CPU tests) or when a dim does
     not divide its mesh axes (falls back to unconstrained for that dim).
     """
-    mesh = ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.shape or int(np.prod(list(
             mesh.shape.values()))) == 1:
         return x

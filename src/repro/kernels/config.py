@@ -134,5 +134,20 @@ def compiler_params(config: KernelConfig):
     if not config.dimension_semantics:
         return None
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.TPUCompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=tuple(config.dimension_semantics))
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Whether a ``pallas_call`` runs in the Pallas interpreter.
+
+    ``None`` (every wrapper's default) derives it from the platform: the
+    interpreter only on the CPU backend, where Mosaic cannot lower, and
+    the compiled kernel everywhere else.  An explicit bool wins, so a
+    compile for a described chip passes ``interpret=False`` from a CPU
+    process.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    import jax
+    return jax.default_backend() == "cpu"

@@ -47,7 +47,7 @@ def _triad_kernel_db(a_ref, b_ref, o_ref, *, scale: float, block: int):
 def triad(a: jax.Array, b: jax.Array, scale: float = 3.0, *,
           config: kc.KernelConfig | None = None,
           block: int | None = None, double_buffer: bool | None = None,
-          interpret: bool = True) -> jax.Array:
+          interpret: bool | None = None) -> jax.Array:
     """o = a·s + b; bytes = 3·N·itemsize, flops = 2·N.  Any N."""
     cfg = kc.resolve("triad", config, block=block,
                      double_buffer=double_buffer)
@@ -73,7 +73,7 @@ def triad(a: jax.Array, b: jax.Array, scale: float = 3.0, *,
         out_specs=pl.BlockSpec((step,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n + pad,), a.dtype),
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
+        interpret=kc.interpret_mode(interpret),
     )(af, bf)
     return out[:n].reshape(a.shape)
 

@@ -48,7 +48,7 @@ def _fma_chain_kernel(x_ref, o_ref, *, n_iters: int, ilp: int):
 def fma_chain(x: jax.Array, n_iters: int = 64, ilp: int = 4, *,
               config: kc.KernelConfig | None = None,
               block: int | None = None,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """Run the FLOP micro-kernel; FLOPs = (2·n_iters·ilp + ilp) · x.size.
 
     The block size comes from the config (tunable); any ``x.size`` works —
@@ -69,7 +69,7 @@ def fma_chain(x: jax.Array, n_iters: int = 64, ilp: int = 4, *,
         out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n + pad,), x.dtype),
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
+        interpret=kc.interpret_mode(interpret),
     )(xf)
     return out[:n].reshape(x.shape)
 

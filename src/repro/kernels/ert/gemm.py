@@ -39,7 +39,7 @@ def matmul(a: jax.Array, b: jax.Array, *,
            config: kc.KernelConfig | None = None,
            block_m: int | None = None, block_n: int | None = None,
            block_k: int | None = None,
-           out_dtype=None, interpret: bool = True) -> jax.Array:
+           out_dtype=None, interpret: bool | None = None) -> jax.Array:
     """C = A @ B with (bm, bn, bk) VMEM tiles; MXU-aligned blocks.
 
     Tile sizes resolve explicit kwargs → ``config`` → the tuner default
@@ -69,7 +69,7 @@ def matmul(a: jax.Array, b: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
+        interpret=kc.interpret_mode(interpret),
     )(a, b)
 
 

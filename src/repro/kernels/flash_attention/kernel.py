@@ -38,9 +38,7 @@ NEG_INF = -1e30
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, block_q: int, block_k: int, sk: int, causal: bool):
     qi = pl.program_id(1)
-    # index the leading block dim with slices, not ints: older pallas
-    # interpreters choke on scalar-int indices in the discharge rules
-    q = q_ref[...][0].astype(jnp.float32)                 # (bq, hd)
+    q = q_ref[0].astype(jnp.float32)                      # (bq, hd)
     scale = q.shape[-1] ** -0.5
     q = q * scale
 
@@ -56,10 +54,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     def body(ki, _):
         k_off = ki * block_k
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(k_off, block_k),
-                            slice(None)))[0].astype(jnp.float32)  # (bk, hd)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(k_off, block_k),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(k_off, block_k), :].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[0, pl.ds(k_off, block_k), :].astype(jnp.float32)
         s = q @ k.T                                       # (bq, bk)
         if causal:
             qpos = q_offset + jax.lax.broadcasted_iota(
@@ -86,7 +82,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     config: kc.KernelConfig | None = None,
                     block_q: int | None = None,
                     block_k: int | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q (BH, Sq, hd), k/v (BH, Sk, hd) → (BH, Sq, hd).
 
     Block sizes resolve explicit kwargs → ``config`` → the 512/512
@@ -118,7 +114,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, hd), jnp.float32),       # acc
         ],
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
+        interpret=kc.interpret_mode(interpret),
     )(q, k, v)
 
 

@@ -50,7 +50,7 @@ def _lookup_config(bh: int, sq: int, sk: int, hd: int, dtype) -> "object":
 
 
 def _kernel_gqa(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
-                interpret: bool, block_q: int | None,
+                interpret: bool | None, block_q: int | None,
                 block_k: int | None) -> jax.Array:
     B, Sq, K, G, hd = q.shape
     _, Sk, _, _ = k.shape
@@ -68,7 +68,7 @@ def _kernel_gqa(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 
 
 @functools.lru_cache(maxsize=16)
-def _make(causal: bool, interpret: bool, block_q: int | None,
+def _make(causal: bool, interpret: bool | None, block_q: int | None,
           block_k: int | None):
     @jax.custom_vjp
     def fa(q, k, v):
@@ -87,7 +87,7 @@ def _make(causal: bool, interpret: bool, block_q: int | None,
 
 
 def flash_attention_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        causal: bool = True, interpret: bool = True,
+                        causal: bool = True, interpret: bool | None = None,
                         block_q: int | None = None,
                         block_k: int | None = None) -> jax.Array:
     """q (B, Sq, K, G, hd), k/v (B, Sk, K, hd) → (B, Sq, K, G, hd).

@@ -49,7 +49,7 @@ def fused_adamw(g: jax.Array, m: jax.Array, v: jax.Array, p: jax.Array,
                 b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
                 weight_decay: float = 0.1,
                 config: kc.KernelConfig | None = None,
-                block: int | None = None, interpret: bool = True
+                block: int | None = None, interpret: bool | None = None
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One leaf's AdamW update in one pass → (new_p, new_m, new_v).
 
@@ -79,7 +79,7 @@ def fused_adamw(g: jax.Array, m: jax.Array, v: jax.Array, p: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((n_blocks * blk,), dt)
                    for dt in (p.dtype, m.dtype, v.dtype)],
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
+        interpret=kc.interpret_mode(interpret),
     )(*flat, bc)
     return tuple(o[:n].reshape(shape) for o in outs)
 

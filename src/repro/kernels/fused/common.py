@@ -19,6 +19,26 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import config as kc
 
+#: VMEM one grid step may hold: half of the 16 MiB Mosaic scopes for a
+#: kernel by default on TPU v5e, the rest left to the compiler's own
+#: temporaries.  A fixed row count cannot honour it: 1024 rows of a
+#: 9216-wide SwiGLU need 180 MB.
+VMEM_BUDGET_BYTES = 8 * 2**20
+#: fp32 row-wide intermediates a kernel body keeps live at once
+_F32_TEMPS = 3
+#: row granularity of a block: the (16, 128) tile of a 16-bit array
+_ROW_TILE = 16
+
+
+def vmem_rows(d: int, dtypes: Sequence[Any]) -> int:
+    """Most rows of width ``d`` one grid step may take within
+    :data:`VMEM_BUDGET_BYTES`: every blocked operand and output is
+    double-buffered, plus the fp32 intermediates."""
+    per_row = d * (2 * sum(jnp.dtype(dt).itemsize for dt in dtypes)
+                   + 4 * _F32_TEMPS)
+    rows = VMEM_BUDGET_BYTES // per_row
+    return max(_ROW_TILE, rows // _ROW_TILE * _ROW_TILE)
+
 
 def pad_rows(x: jax.Array, block: int) -> jax.Array:
     """Zero-pad dim 0 up to a multiple of ``block`` (no-op if aligned)."""
@@ -32,16 +52,18 @@ def pad_rows(x: jax.Array, block: int) -> jax.Array:
 def row_blocked_call(kernel: Callable, row_args: Sequence[jax.Array],
                      shared_args: Sequence[jax.Array],
                      out_dtypes: Sequence[Any], cfg: kc.KernelConfig, *,
-                     interpret: bool = True) -> tuple[jax.Array, ...]:
+                     interpret: bool | None = None) -> tuple[jax.Array, ...]:
     """Launch ``kernel`` over row blocks of 2D ``(rows, d)`` operands.
 
-    ``row_args`` are blocked over dim 0; ``shared_args`` (1D, e.g. norm
-    scale/bias) are broadcast to every block.  Outputs mirror the row
-    layout, one per entry of ``out_dtypes``, and are sliced back to the
-    unpadded row count.
+    ``row_args`` are blocked over dim 0 in blocks of the config's
+    ``block_rows``, capped by :func:`vmem_rows`; ``shared_args`` (1D,
+    e.g. norm scale/bias) are broadcast to every block.  Outputs mirror
+    the row layout, one per entry of ``out_dtypes``, and are sliced back
+    to the unpadded row count.
     """
     rows, d = row_args[0].shape
-    block = min(int(cfg.get("block_rows")), rows)
+    block = min(int(cfg.get("block_rows")), rows,
+                vmem_rows(d, [a.dtype for a in row_args] + list(out_dtypes)))
     padded = [pad_rows(a, block) for a in row_args]
     n_blocks = padded[0].shape[0] // block
 
@@ -62,7 +84,7 @@ def row_blocked_call(kernel: Callable, row_args: Sequence[jax.Array],
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
+        interpret=kc.interpret_mode(interpret),
     )(*padded, *shared_args)
     outs = (out,) if len(out_dtypes) == 1 else tuple(out)
     if outs[0].shape[0] != rows:

@@ -55,7 +55,7 @@ def _layernorm_kernel(x_ref, s_ref, b_ref, y_ref, *, eps: float):
 def fused_rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-5,
                   out_dtype=None, config: kc.KernelConfig | None = None,
                   block_rows: int | None = None,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """x (rows, d), scale (d,) → rmsnorm(x)·scale as ``out_dtype``."""
     cfg = kc.resolve("fused_norm", config, block_rows=block_rows)
     (y,) = row_blocked_call(
@@ -68,7 +68,7 @@ def fused_rmsnorm_residual(x: jax.Array, h: jax.Array, scale: jax.Array, *,
                            eps: float = 1e-5, out_dtype=None,
                            config: kc.KernelConfig | None = None,
                            block_rows: int | None = None,
-                           interpret: bool = True
+                           interpret: bool | None = None
                            ) -> tuple[jax.Array, jax.Array]:
     """(x + h, rmsnorm(x + h)·scale) in one pass; x/h (rows, d)."""
     cfg = kc.resolve("fused_norm", config, block_rows=block_rows)
@@ -82,7 +82,7 @@ def fused_layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array, *,
                     eps: float = 1e-5, out_dtype=None,
                     config: kc.KernelConfig | None = None,
                     block_rows: int | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """x (rows, d), scale/bias (d,) → layernorm(x)·scale + bias."""
     cfg = kc.resolve("fused_norm", config, block_rows=block_rows)
     (y,) = row_blocked_call(

@@ -32,7 +32,7 @@ def _swiglu_kernel(g_ref, u_ref, o_ref, *, act: str):
 def fused_swiglu(gate: jax.Array, up: jax.Array, *, act: str = "silu",
                  out_dtype=None, config: kc.KernelConfig | None = None,
                  block_rows: int | None = None,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """gate/up (rows, d_ff) → act(gate)·up as ``out_dtype``."""
     if act not in ACTS:
         raise ValueError(f"unknown activation {act!r}; known: {ACTS}")

@@ -39,35 +39,40 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)                  # (Q, P)
-    a = a_ref[0, 0].astype(jnp.float32)                  # (Q,)
+    a = a_ref[0, 0].astype(jnp.float32)                  # (1, Q)
     Bc = b_ref[0].astype(jnp.float32)                    # (Q, N)
     Cc = c_ref[0].astype(jnp.float32)                    # (Q, N)
 
-    cum = jnp.cumsum(a)                                  # (Q,)
-    total = cum[-1]
-
-    # intra-chunk: M[i,j] = 1[i>=j] · exp(cum_i - cum_j) · (C_i · B_j)
-    seg = cum[:, None] - cum[None, :]
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(jnp.where(iq >= jq, seg, -jnp.inf))   # mask pre-exp
+    causal = iq >= jq
+    # inclusive prefix sum as a column, cum_i = sum_{j<=i} a_j: a masked
+    # lane reduction, since Mosaic lowers no cumsum
+    cum = jnp.sum(jnp.where(causal, a, 0.0), axis=1, keepdims=True)  # (Q, 1)
+    total = jnp.sum(a)
+
+    # intra-chunk: M[i,j] = 1[i>=j] · exp(cum_i - cum_j) · (C_i · B_j)
+    cum_sq = jnp.broadcast_to(cum, (chunk, chunk))
+    seg = cum_sq - cum_sq.T
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))    # mask pre-exp
     scores = Cc @ Bc.T                                   # (Q, Q)
     y = (scores * decay) @ x                             # (Q, P)
 
     # inter-chunk: y_i += exp(cum_i) · C_i · state_prev
-    y = y + jnp.exp(cum)[:, None] * (Cc @ state_ref[...].T)
+    y = y + jnp.exp(cum) * (Cc @ state_ref[...].T)
 
     # state update: s = exp(total)·s + Σ_j exp(total - cum_j) x_j B_jᵀ
-    w = jnp.exp(total - cum)                             # (Q,)
+    w = jnp.exp(total - cum)                             # (Q, 1)
     state_ref[...] = (jnp.exp(total) * state_ref[...]
-                      + x.T @ (Bc * w[:, None]))         # (P, N)
+                      + x.T @ (Bc * w))                  # (P, N)
 
     o_ref[0, 0] = y.astype(o_ref.dtype)
 
 
 def ssd_scan(xdt: jax.Array, a: jax.Array, B_: jax.Array, C_: jax.Array, *,
              config: kc.KernelConfig | None = None,
-             chunk: int | None = None, interpret: bool = True) -> jax.Array:
+             chunk: int | None = None,
+             interpret: bool | None = None) -> jax.Array:
     """xdt (B, H, S, P), a (B, H, S), B_/C_ (B, S, N) → y (B, H, S, P).
 
     ``chunk`` resolves explicit kwarg → ``config`` → the 128 default; the
@@ -86,7 +91,10 @@ def ssd_scan(xdt: jax.Array, a: jax.Array, B_: jax.Array, C_: jax.Array, *,
         grid=(Bsz, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
+            # a rides as (B, H, 1, S): a (1, chunk) tile is a legal TPU
+            # block (unit dim = the array's, chunk a multiple of 128);
+            # (1, 1, chunk) over (B, H, S) would tile H by 1
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
@@ -94,8 +102,8 @@ def ssd_scan(xdt: jax.Array, a: jax.Array, B_: jax.Array, C_: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((Bsz, H, S, P), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         compiler_params=kc.compiler_params(cfg),
-        interpret=interpret,
-    )(xdt, a, B_, C_)
+        interpret=kc.interpret_mode(interpret),
+    )(xdt, a[:, :, None, :], B_, C_)
 
 
 def hbm_bytes(b: int, h: int, s: int, p: int, n: int,

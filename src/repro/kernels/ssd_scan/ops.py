@@ -29,7 +29,7 @@ def _lookup_chunk(b: int, h: int, s: int, p: int, n: int, dtype) -> int:
 
 
 @functools.lru_cache(maxsize=8)
-def _make(chunk: int | None, interpret: bool):
+def _make(chunk: int | None, interpret: bool | None):
     def _ref(xh, a, B_, C_, q):
         from repro.models.ssm import ssd_chunked
         y, _ = ssd_chunked(xh, a, B_, C_, min(q, xh.shape[1]))
@@ -64,7 +64,7 @@ def _make(chunk: int | None, interpret: bool):
 def ssd_scan_model_layout(xh: jax.Array, a_log_dt: jax.Array,
                           B_: jax.Array, C_: jax.Array,
                           chunk: int | None = None,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """xh (B, S, H, P), a_log_dt (B, S, H), B_/C_ (B, S, N) → (B, S, H, P).
 
     ``chunk=None`` → the tuned winner for this shape (default 128).

@@ -168,9 +168,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     t0 = time.time()
     cfg, run, fn, arg_specs, donate = build_cell(arch, shape, mesh, run)
-    from repro.core.compat import mesh_context
     jitted = jax.jit(fn, donate_argnums=donate)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*arg_specs)
         compiled = lowered.compile()
     t_compile = time.time() - t0

@@ -27,6 +27,7 @@ from repro.models.params import init
 from repro.serve.engine import SERVABLE_FAMILIES, Engine
 from repro.serve.workload import make_trace
 from repro.checkpoint import checkpointer as ckpt
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -43,6 +44,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill-chunk", type=int, default=16)
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--amp", default="O1", choices=("O0", "O1", "O2"),
+                    help="precision policy; O2 keeps the weights in bf16")
     ap.add_argument("--ckpt", default=None, help="restore params from here")
     args = ap.parse_args(argv)
 
@@ -52,9 +55,10 @@ def main(argv=None) -> int:
               f"{SERVABLE_FAMILIES}; {cfg.family} models decode via "
               "repro.models.api decode_fn")
         return 2
-    run = RunConfig(amp="O1")
+    enable_compile_cache()
+    run = RunConfig(amp=args.amp)
     model = build(cfg)
-    params = init(jax.random.PRNGKey(args.seed), model.spec)
+    params = init(jax.random.PRNGKey(args.seed), model.spec, run.param_dtype)
     if args.ckpt:
         params, _ = ckpt.restore(args.ckpt, params)
 

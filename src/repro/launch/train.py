@@ -21,6 +21,7 @@ import sys
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import RunConfig, ShapeSpec
 from repro.configs.registry import ALL, get_config, get_smoke
 from repro.data.pipeline import ClimateStream, TokenStream
@@ -29,6 +30,39 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import api as M
 from repro.train import step as TS
 from repro.train.trainer import Trainer
+
+
+def build_trainer(arch: str, run: RunConfig, *, batch: int, seq: int = 128,
+                  smoke: bool = False, lr: float = 3e-4,
+                  ckpt: str | None = None, ckpt_every: int = 50,
+                  devices=None, seed: int = 0) -> Trainer:
+    """A :class:`Trainer` for a registry config, data-parallel over
+    ``devices`` (default: every device of this process) with the repo's
+    parameter, optimizer-state and batch shardings."""
+    cfg = get_smoke(arch) if smoke else get_config(arch)
+    model = M.build(cfg)
+    shape = ShapeSpec("cli", seq, batch, "train")
+
+    mesh = make_host_mesh(devices)
+    state_abs = TS.abstract_state(model, run)
+    pshard = shd.param_shardings(model.spec, mesh, run)
+    oshard = shd.opt_state_shardings(state_abs.opt, pshard, mesh)
+    rep = shd.replicated(mesh)
+    state_sh = TS.TrainState(
+        params=pshard, opt=oshard,
+        loss_scale=jax.tree.map(lambda _: rep, state_abs.loss_scale),
+        step=rep)
+    batch_sh = shd.shard_batch_dim(M.input_specs(cfg, shape), mesh, run)
+
+    if cfg.family == "cnn":
+        from repro.configs.deepcam import IMAGE_HW, SMOKE_HW
+        stream = ClimateStream(SMOKE_HW if smoke else IMAGE_HW, batch)
+    else:
+        stream = TokenStream(cfg, shape, batch)
+
+    return Trainer(model, run, stream, ckpt_dir=ckpt, ckpt_every=ckpt_every,
+                   lr=lr, mesh=mesh, state_shardings=state_sh,
+                   batch_shardings=batch_sh, seed=seed)
 
 
 def main(argv=None) -> int:
@@ -48,33 +82,12 @@ def main(argv=None) -> int:
                     choices=("none", "dots", "full"))
     args = ap.parse_args(argv)
 
-    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    enable_compile_cache()
     run = RunConfig(amp=args.amp, remat=args.remat,
                     microbatches=args.microbatches)
-    model = M.build(cfg)
-    shape = ShapeSpec("cli", args.seq, args.batch, "train")
-
-    mesh = make_host_mesh()
-    state_abs = TS.abstract_state(model, run)
-    pshard = shd.param_shardings(model.spec, mesh, run)
-    oshard = shd.opt_state_shardings(state_abs.opt, pshard, mesh)
-    rep = shd.replicated(mesh)
-    state_sh = TS.TrainState(
-        params=pshard, opt=oshard,
-        loss_scale=jax.tree.map(lambda _: rep, state_abs.loss_scale),
-        step=rep)
-    batch_sh = shd.shard_batch_dim(M.input_specs(cfg, shape), mesh, run)
-
-    if cfg.family == "cnn":
-        from repro.configs.deepcam import IMAGE_HW, SMOKE_HW
-        hw = SMOKE_HW if args.smoke else IMAGE_HW
-        stream = ClimateStream(hw, args.batch)
-    else:
-        stream = TokenStream(cfg, shape, args.batch)
-
-    trainer = Trainer(model, run, stream, ckpt_dir=args.ckpt,
-                      ckpt_every=args.ckpt_every, lr=args.lr, mesh=mesh,
-                      state_shardings=state_sh, batch_shardings=batch_sh)
+    trainer = build_trainer(args.arch, run, batch=args.batch, seq=args.seq,
+                            smoke=args.smoke, lr=args.lr, ckpt=args.ckpt,
+                            ckpt_every=args.ckpt_every)
     report = trainer.fit(args.steps)
     print(f"[train] {report.steps} steps, final loss "
           f"{report.losses[-1]:.4f}, mean step "

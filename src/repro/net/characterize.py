@@ -200,7 +200,8 @@ def characterize_net(machine: Any = "cpu-host", *,
     devices — tests force the count before importing jax); the default
     spawns one supervised worker that pins
     ``--xla_force_host_platform_device_count`` first, exactly like a
-    sweep point.
+    sweep point.  On an accelerator the measurement always runs here,
+    over the real devices: this process holds them.
     """
     from repro.tune.store import _as_store
     name = machine if isinstance(machine, str) else machine.name
@@ -220,7 +221,9 @@ def characterize_net(machine: Any = "cpu-host", *,
         raise ValueError(f"n_devices must be even (the dcn leg splits the "
                          f"ring into two pods), got {n_devices}")
 
-    if inline:
+    from repro.resilience.watchdog import holds_accelerator
+    if inline or holds_accelerator():
+        # on an accelerator this process holds the chips: measure here
         rows = measure_collectives(n_devices, sizes, iters=iters,
                                    warmup=warmup)
     else:

@@ -21,10 +21,13 @@ import argparse
 import sys
 from typing import Sequence
 
+from repro.compile_cache import enable_compile_cache
+
 PROG = "python -m repro net"
 
 
 def cmd_characterize(args) -> int:
+    enable_compile_cache()
     from repro.net.characterize import characterize_net
     try:
         out = characterize_net(

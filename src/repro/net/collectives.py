@@ -73,7 +73,7 @@ def _collective_fns(n_devices: int, leg: str):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
     if leg == "dcn":
         if n_devices % 2:

@@ -19,6 +19,12 @@ campaign) forever.  :class:`SupervisedPool` owns its workers directly:
 Retry / backoff / quarantine policy deliberately lives in the caller
 (``repro.sweep.engine``): the pool only answers "what happened to this
 attempt", so the same machinery can supervise any picklable job.
+
+Workers exist for the CPU host.  An accelerator belongs to one process
+at a time, and the process that asked JAX for its backend holds it, so
+a worker could never reach the chip: it would fail or hang.  The pool
+refuses to start there (:func:`holds_accelerator`), and its callers run
+their measured work in the process that holds the chip instead.
 """
 
 from __future__ import annotations
@@ -33,6 +39,10 @@ from typing import Any, Callable, Sequence
 _GRACE_S = 1.0
 #: pipe poll quantum — also bounds deadline-detection latency
 _POLL_S = 0.1
+#: least time a spawned worker gets to start its interpreter and run its
+#: initializer before it counts as stuck: a task deadline is for the
+#: task, and a fresh interpreter's imports alone can outlast a short one
+_BOOT_S = 30.0
 
 
 @dataclasses.dataclass
@@ -107,6 +117,13 @@ def _child_main(conn, init, initargs, worker_fn) -> None:
             return
 
 
+def holds_accelerator() -> bool:
+    """True when this process computes on an accelerator, and therefore
+    holds the chip.  Initialises JAX's backend to find out."""
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 class SupervisedPool:
     """Run picklable tasks on supervised workers with a per-task deadline.
 
@@ -122,6 +139,10 @@ class SupervisedPool:
         import multiprocessing
         if n_workers < 1:
             raise ValueError(f"n_workers must be >= 1, got {n_workers}")
+        if holds_accelerator():
+            raise RuntimeError(
+                "this process holds the accelerator, so a worker process "
+                "could not reach it; run the work in this process instead")
         self.worker_fn = worker_fn
         self.n_workers = n_workers
         self.init, self.initargs = init, initargs
@@ -266,7 +287,8 @@ class SupervisedPool:
                     self.replacements += 1
                 elif (self.deadline_s is not None and not w.ready
                         and w.task is None
-                        and now - w.t_spawn > self.deadline_s):
+                        and now - w.t_spawn > max(self.deadline_s,
+                                                  _BOOT_S)):
                     # stuck in spawn bootstrap / init: it holds no task, but
                     # left alone it would absorb the worker slot forever
                     w.kill()

@@ -136,6 +136,13 @@ class Engine:
             self._compiled[name] = build_fn()
         return self._compiled[name]
 
+    def warmup(self) -> None:
+        """Compile every executable now.  Compilation is set-up: left to
+        the first request, it would land in that request's time to first
+        token."""
+        for name in PHASE_OF:
+            self.executable(name)
+
     def _timed(self, name: str, *args):
         fn = self.executable(name)
         t0 = time.perf_counter()
@@ -404,12 +411,10 @@ class Engine:
     # the tick
     # ------------------------------------------------------------------
 
-    def _prefill_step(self, slot_idx: int) -> None:
-        """Advance one prefilling slot by one prompt chunk."""
-        slot = self._slots[slot_idx]
-        req = slot.req
-        prompt = np.asarray(req.prompt, np.int32)
-        start = slot.filled
+    def _prefill_chunk(self, slot_idx: int, prompt: np.ndarray,
+                       start: int):
+        """Run the prompt chunk at ``start`` for an allocated slot,
+        writing its K/V to the pool → (last-position logits, tokens)."""
         valid = min(self.chunk, len(prompt) - start)
         chunk = np.zeros(self.chunk, np.int32)
         chunk[:valid] = prompt[start:start + valid]
@@ -430,8 +435,16 @@ class Engine:
                 jnp.asarray(self.cache.page_table[slot_idx]),
                 jnp.asarray(wpage), jnp.asarray(woff))
         self.cache.k_pool, self.cache.v_pool = kp, vp
-        slot.filled = start + valid
-        self.cache.lengths[slot_idx] = slot.filled
+        self.cache.lengths[slot_idx] = start + valid
+        return logits, valid
+
+    def _prefill_step(self, slot_idx: int) -> None:
+        """Advance one prefilling slot by one prompt chunk."""
+        slot = self._slots[slot_idx]
+        req = slot.req
+        prompt = np.asarray(req.prompt, np.int32)
+        logits, valid = self._prefill_chunk(slot_idx, prompt, slot.filled)
+        slot.filled += valid
         if slot.filled < len(prompt):
             return                          # more chunks next tick
         # prompt complete: the chunk's last logits give the first token
@@ -442,6 +455,25 @@ class Engine:
         slot.next_tok = tok
         slot.phase = "decode"
         self._maybe_finish(slot_idx, tok)
+
+    def prompt_logits(self, prompt) -> jax.Array:
+        """Last-position logits of ``prompt`` through the chunked-prefill
+        executables every request's first token comes from.  Runs in a
+        free slot, whose pages are released again before returning."""
+        prompt = np.asarray(prompt, np.int32)
+        slot = self._slots.index(None)       # ValueError: no free slot
+        if not 0 < len(prompt) <= self.max_len \
+                or not self.cache.alloc(slot, len(prompt)):
+            raise ValueError(f"cannot prefill a {len(prompt)}-token "
+                             "prompt here")
+        try:
+            start = 0
+            while start < len(prompt):
+                logits, valid = self._prefill_chunk(slot, prompt, start)
+                start += valid
+        finally:
+            self.cache.release(slot)
+        return logits
 
     def _maybe_finish(self, slot_idx: int, tok: int) -> None:
         """Completion checks after a token landed; frees the slot."""
@@ -535,6 +567,7 @@ class Engine:
         """
         from repro.serve.metrics import stats_from_requests
 
+        self.warmup()
         t0 = time.perf_counter()
         start_tick = self.tick_count
         pending = sorted(requests, key=lambda r: r.arrival)

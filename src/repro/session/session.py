@@ -45,16 +45,20 @@ def _matmul_class(run: Any) -> str | None:
 class Session:
     """One analysis session: a machine model + a workspace + the workflow.
 
-    ``machine`` is a :class:`MachineSpec` or a registry name
-    (``cpu-host``, ``tpu-v5e``, ...); ``workspace`` is a
+    ``machine`` is a :class:`MachineSpec`, a registry name
+    (``cpu-host``, ``tpu-v5e``, ...) or ``None`` for the model of the
+    device this process computes on; ``workspace`` is a
     :class:`Workspace`, a root path, or ``None`` for the default root
     (``REPRO_WORKSPACE`` > ``./.repro-workspace`` in a checkout >
     ``~/.repro``).
     """
 
-    def __init__(self, machine: "MachineSpec | str" = "cpu-host",
+    def __init__(self, machine: "MachineSpec | str | None" = None,
                  workspace: Workspace | str | None = None):
-        from repro.core.machine import MachineSpec, get_machine
+        from repro.core.machine import (MachineSpec, get_machine,
+                                        machine_for_device)
+        if machine is None:
+            machine = machine_for_device()
         self.machine = (machine if isinstance(machine, MachineSpec)
                         else get_machine(machine))
         self.workspace = (workspace if isinstance(workspace, Workspace)
@@ -222,7 +226,9 @@ class Session:
         per serving phase: decode is bandwidth-dominated at small batch,
         chunked prefill sits far closer to the compute ceiling.
         ``exit_code`` is 1 when the latency gate fails (a wedged
-        scheduler, an admitted request that never finished).
+        scheduler, an admitted request that never finished).  ``data``
+        is ``(record, stats, engine)``; the engine still holds the
+        weights and its compiled executables.
         """
         import jax
 
@@ -237,7 +243,8 @@ class Session:
 
         cfg = get_smoke(config) if smoke else get_config(config)
         run = RunConfig(amp=amp, fusion=fusion)
-        params = init(jax.random.PRNGKey(seed), M.build(cfg).spec)
+        params = init(jax.random.PRNGKey(seed), M.build(cfg).spec,
+                      run.param_dtype)
         engine = Engine(cfg, run, params, n_slots=n_slots, max_len=max_len,
                         page_size=page_size, prefill_chunk=prefill_chunk)
         pl = (min(prompt_len[0], max_len), min(prompt_len[1], max_len))
@@ -271,7 +278,7 @@ class Session:
             provenance=self._provenance(run_id=rec.run_id,
                                         store=self.workspace.trace_path),
             phases=phases_from_record(rec),
-            text=text, data=(rec, stats),
+            text=text, data=(rec, stats, engine),
             exit_code=1 if problems else 0)
 
     # -- 4. read back without re-running ---------------------------------

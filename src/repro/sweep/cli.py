@@ -30,6 +30,7 @@ import dataclasses
 import sys
 from typing import Sequence
 
+from repro.compile_cache import enable_compile_cache
 from repro.session.workspace import (LEGACY_SWEEP_CACHE, LEGACY_SWEEP_STORE,
                                      resolve_sweep_cache, resolve_sweep_store)
 from repro.sweep.spec import (SweepSpec, parse_int_list, parse_mesh,
@@ -105,6 +106,7 @@ def cmd_run(ap: argparse.ArgumentParser, args) -> int:
         return 2
     print(f"[{spec.name}] {len(points)} point(s) "
           f"({len(skipped)} skipped) -> {args.store}")
+    enable_compile_cache()
     result = run_sweep(
         spec, store_path=args.store, workers=args.workers,
         cache_dir=None if args.no_cache else resolve_sweep_cache(

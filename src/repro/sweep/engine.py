@@ -44,7 +44,7 @@ from typing import Any, Callable, Mapping
 
 from repro.resilience import faults
 from repro.resilience.journal import CampaignJournal, journal_path_for
-from repro.resilience.watchdog import SupervisedPool
+from repro.resilience.watchdog import SupervisedPool, holds_accelerator
 from repro.session.workspace import (LEGACY_SWEEP_CACHE, LEGACY_SWEEP_STORE,
                                      resolve_sweep_cache,
                                      resolve_sweep_store)
@@ -407,6 +407,7 @@ def run_sweep(sweep: SweepSpec, *, store_path: str | None = None,
 
     ``workers``: pool size; ``0`` runs every point inline in this process
     (single-device points only — useful under pytest and for debugging).
+    A process on an accelerator always runs inline: it holds the chip.
     ``None`` picks ``min(4, cpu_count)`` for analytical sweeps but ``1``
     for measured ones: concurrent wall-clock samples contend for the same
     CPUs and skew each other, so parallel measurement is opt-in.
@@ -439,6 +440,11 @@ def run_sweep(sweep: SweepSpec, *, store_path: str | None = None,
 
     if workers is None:
         workers = 1 if sweep.measure else min(4, os.cpu_count() or 1)
+    if workers and holds_accelerator():
+        # the chip is this process's: a worker could never reach it
+        say("[sweep] this process holds the accelerator; running every "
+            "point in it")
+        workers = 0
 
     done_ids = (_resume_run_ids(store, journal, sweep.name)
                 if resume else {})

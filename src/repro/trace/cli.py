@@ -32,6 +32,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import FUSION_MODES, RunConfig, ShapeSpec
 from repro.configs.registry import ALL, ARCHS, get_config, get_smoke
 from repro.core.machine import MACHINES
@@ -120,8 +121,12 @@ def scale_measurement(m: PhaseMeasurement, factor: float) -> PhaseMeasurement:
 
 
 def cmd_record(args) -> int:
+    enable_compile_cache()
     from repro.core.report import achieved_table
     args.store = resolve_trace_store(args.store)
+    if args.machine is None:
+        from repro.core.machine import machine_for_device
+        args.machine = machine_for_device().name
     store = TraceStore(args.store)
     configs = list(ARCHS) if args.all else (args.config or [])
     if not configs:
@@ -247,10 +252,10 @@ def add_record_parser(sub):
     rec.add_argument("--all", action="store_true",
                      help=f"sweep all {len(ARCHS)} assigned archs")
     _add_store(rec)
-    rec.add_argument("--machine", default="cpu-host",
+    rec.add_argument("--machine", default=None,
                      choices=sorted(MACHINES),
                      help="machine model the %%-of-roofline is against "
-                          "(default cpu-host: honest numbers off-TPU)")
+                          "(default: the model of this process's device)")
     rec.add_argument("--iters", type=int, default=5)
     rec.add_argument("--warmup", type=int, default=2)
     rec.add_argument("--seq", type=int, default=32)

@@ -59,6 +59,8 @@ class PhaseMeasurement:
     flops: float                    # per-device HLO FLOPs
     hbm_bytes: float
     vmem_bytes: float = 0.0         # per-device internal (VMEM-level) bytes
+    xla_flops: float = 0.0          # XLA's cost_analysis FLOPs (cross-check)
+    xla_transcendentals: float = 0.0    # ... and transcendentals, apart
 
     @property
     def achieved_flops_per_s(self) -> float:
@@ -156,7 +158,9 @@ def measurement_from_profile(res: ProfileResult,
         kernels=attribute_time(res.analysis, machine, res.wall_s),
         flops=res.analysis.total_flops,
         hbm_bytes=res.analysis.total_hbm_bytes,
-        vmem_bytes=res.analysis.total_vmem_bytes)
+        vmem_bytes=res.analysis.total_vmem_bytes,
+        xla_flops=res.xla_flops,
+        xla_transcendentals=res.xla_transcendentals)
 
 
 def collect_phase(name: str, fn: Callable, args: Sequence[Any], *,

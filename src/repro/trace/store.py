@@ -21,7 +21,6 @@ import dataclasses
 import json
 import os
 import platform
-import subprocess
 import sys
 import time
 import uuid
@@ -37,29 +36,71 @@ PHASE_METRICS = ("wall_s", "achieved_flops_per_s", "pct_of_roofline",
                  "bound_overlap_s", "bound_serial_s")
 
 
+#: the checkout this package sits in (``src/repro/trace`` -> its root)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def _read(path: str) -> str:
+    with open(path) as f:
+        return f.read().strip()
+
+
 def git_sha(repo_root: str | None = None) -> str:
-    """HEAD commit of the repo containing this file (or ``repo_root``)."""
-    root = repo_root or os.path.dirname(os.path.abspath(__file__))
+    """HEAD commit of the checkout at ``repo_root`` (default: the one
+    this package sits in).
+
+    Read from the checkout's own ``.git`` rather than by running ``git``:
+    the measuring process starts no other process.  ``.git`` is a
+    directory, or in a worktree a file whose ``gitdir:`` line names the
+    worktree's git directory; branches then live in the directory its
+    ``commondir`` names.  Nothing above ``repo_root`` is looked at: a
+    tree without its own ``.git`` (an export, an unpacked archive) is
+    ``"unknown"``, never the HEAD of a repository around it.
+    """
+    dot = os.path.join(repo_root or REPO_ROOT, ".git")
     try:
-        out = subprocess.run(
-            ["git", "rev-parse", "HEAD"], cwd=root, capture_output=True,
-            text=True, timeout=10)
-        if out.returncode == 0:
-            return out.stdout.strip()
-    except (OSError, subprocess.SubprocessError):
+        git = dot
+        if os.path.isfile(dot):
+            line = _read(dot)
+            if not line.startswith("gitdir: "):
+                return "unknown"
+            git = os.path.join(os.path.dirname(dot), line[len("gitdir: "):])
+        head = _read(os.path.join(git, "HEAD"))
+        if not head.startswith("ref: "):
+            return head
+        ref = head[len("ref: "):]
+        common = git
+        if os.path.isfile(os.path.join(git, "commondir")):
+            common = os.path.join(git, _read(os.path.join(git, "commondir")))
+        for d in (git, common):
+            if os.path.isfile(os.path.join(d, ref)):
+                return _read(os.path.join(d, ref))
+        with open(os.path.join(common, "packed-refs")) as f:
+            for line in f:
+                if line.rstrip().endswith(" " + ref):
+                    return line.split()[0]
+    except OSError:
         pass
     return "unknown"
 
 
 def host_fingerprint() -> dict[str, str]:
-    """Where the measurement ran (cross-host comparisons need a warning)."""
+    """Where the measurement ran (cross-host comparisons need a warning).
+
+    Reads the devices, so it initialises JAX's backend: call it from the
+    process that measures, never from a parent about to start workers.
+    """
     import jax
+    devices = jax.devices()
     return {
         "host": platform.node(),
         "platform": platform.platform(),
         "python": sys.version.split()[0],
         "jax": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": devices[0].device_kind,
+        "device_count": str(len(devices)),
     }
 
 
@@ -131,6 +172,8 @@ def phase_payload(m: PhaseMeasurement, top_kernels: int = 8
         "collective_s": t.collective_s,
         "dominant": m.dominant,
         "flops": m.flops,
+        "xla_flops": m.xla_flops,
+        "xla_transcendentals": m.xla_transcendentals,
         "hbm_bytes": m.hbm_bytes,
         "vmem_bytes": m.vmem_bytes,
         # interconnect level (third roofline hierarchy level): algorithm-

@@ -46,6 +46,7 @@ from repro.train.step import TrainState, init_state, make_train_step
 class TrainReport:
     steps: int = 0
     losses: list = dataclasses.field(default_factory=list)
+    grad_norms: list = dataclasses.field(default_factory=list)
     step_times: list = dataclasses.field(default_factory=list)
     stragglers: list = dataclasses.field(default_factory=list)
     restarts: int = 0
@@ -187,11 +188,12 @@ class Trainer:
                 loss = float(metrics["loss"])
                 self.report.steps += 1
                 self.report.losses.append(loss)
+                self.report.grad_norms.append(float(metrics["grad_norm"]))
                 self.report.step_times.append(dt)
                 if log_every and i % log_every == 0:
                     log(f"[trainer] step {i:5d} loss {loss:.4f} "
                         f"({dt*1e3:.1f} ms, grad_norm "
-                        f"{float(metrics['grad_norm']):.3f})")
+                        f"{self.report.grad_norms[-1]:.3f})")
                     if not self._async_ckpt.healthy():
                         log(f"[trainer] checkpoint writer failed; "
                             f"surfacing at step {i}")

@@ -42,6 +42,7 @@ import time
 import traceback
 from typing import Sequence
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.machine import MACHINES
 from repro.tune import space as sp
 from repro.tune.search import search, search_all, tune_ceilings
@@ -56,6 +57,7 @@ def _parse_shape(text: str) -> tuple[int, ...]:
 
 
 def cmd_search(args) -> int:
+    enable_compile_cache()
     store = TuneStore(args.store)
     known = (sp.XLA_KERNELS if args.backend == "xla"
              else sp.PALLAS_KERNELS)
@@ -121,6 +123,7 @@ def cmd_show(args) -> int:
 
 
 def cmd_apply(args) -> int:
+    enable_compile_cache()
     from repro.tune.search import _time_candidate
     store = TuneStore(args.store)
     recs = [r for r in store.records() if r.backend == "pallas"]
@@ -135,7 +138,8 @@ def cmd_apply(args) -> int:
         tuned = next((c for c in cands if c.dict == r.params), None)
         default = next(
             (c for c in cands
-             if sp.is_default(r.kernel, "pallas", r.shape, c.dict)), None)
+             if sp.is_default(r.kernel, "pallas", r.shape, c.dict,
+                              r.dtype)), None)
         if tuned is None or default is None:
             print(f"[stale] {r.kernel} {r.shape}: stored params "
                   f"{r.params} no longer in the search space — re-search")
@@ -156,6 +160,7 @@ def cmd_apply(args) -> int:
 
 
 def cmd_dispatch_search(args) -> int:
+    enable_compile_cache()
     from repro.tune import dispatch as dsp
     store = TuneStore(args.store)
     try:
@@ -195,6 +200,7 @@ def cmd_dispatch_show(args) -> int:
 
 
 def cmd_dispatch_apply(args) -> int:
+    enable_compile_cache()
     from repro.tune import dispatch as dsp
     store = TuneStore(args.store)
     recs = dsp.dispatch_table(store)

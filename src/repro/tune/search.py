@@ -90,7 +90,8 @@ def search(kernel: str, shape: Sequence[int] | None = None,
         metric = (cand.work / wall) if wall > 0 else 0.0
         results.append(CandidateResult(
             cand.dict, wall, metric,
-            is_default=sp.is_default(kernel, backend, shape, cand.dict)))
+            is_default=sp.is_default(kernel, backend, shape, cand.dict,
+                                     dtype)))
 
     best = max(results, key=lambda r: r.metric)
     default = next(r for r in results if r.is_default)

@@ -287,10 +287,23 @@ def _ssd_pallas(shape, dtype, smoke):
 # padding and are skipped — except the hardcoded default, which must stay
 # in every space for the honest before/after pair.
 
-def _row_blocks(rows: int, dflt: int, smoke: bool) -> list[int]:
+def _row_cap(kernel: str, d: int, dtype: str) -> int:
+    """The VMEM bound ``row_blocked_call`` puts on a row block of width
+    ``d``: the norm+residual kernel the tuner times streams x and h in and
+    r and y out, SwiGLU gate and up in and the product out, all in
+    ``dtype``."""
+    from repro.kernels.fused.common import vmem_rows
+    streams = 4 if kernel == "fused_norm" else 3
+    return vmem_rows(d, [_dtype(dtype)] * streams)
+
+
+def _row_blocks(rows: int, dflt: int, smoke: bool, cap: int) -> list[int]:
+    """Row-block candidates, each capped at ``cap`` (:func:`_row_cap`) as
+    the kernel caps it, so a stored winner names the block that ran."""
     blocks = (128, 1024) if smoke else (128, 256, 1024, 4096)
+    dflt = min(dflt, cap)
     out = []
-    for blk in dict.fromkeys((*blocks, dflt)):
+    for blk in dict.fromkeys(min(b, cap) for b in (*blocks, dflt)):
         if blk > rows and blk != dflt:
             continue
         out.append(blk)
@@ -307,7 +320,8 @@ def _fused_norm_pallas(shape, dtype, smoke):
     work = nk.hbm_bytes(rows, d, np.dtype(dt).itemsize, residual=True)
     dflt = default_config("fused_norm").get("block_rows")
     out = []
-    for blk in _row_blocks(rows, dflt, smoke):
+    for blk in _row_blocks(rows, dflt, smoke,
+                           _row_cap("fused_norm", d, dtype)):
 
         def build(blk=blk):
             import jax
@@ -333,7 +347,8 @@ def _fused_swiglu_pallas(shape, dtype, smoke):
     work = sk.hbm_bytes(rows, d, np.dtype(dt).itemsize)
     dflt = default_config("fused_swiglu").get("block_rows")
     out = []
-    for blk in _row_blocks(rows, dflt, smoke):
+    for blk in _row_blocks(rows, dflt, smoke,
+                           _row_cap("fused_swiglu", d, dtype)):
 
         def build(blk=blk):
             import jax
@@ -476,19 +491,20 @@ def candidates(kernel: str, shape: Sequence[int], dtype: str = "float32",
         raise ValueError(f"{kernel}: no feasible candidate for shape "
                          f"{tuple(shape)} — every block choice was "
                          "incompatible")
-    dflt = _clamped_default(kernel, backend, shape)
+    dflt = _clamped_default(kernel, backend, shape, dtype)
     if not any(c.dict == dflt for c in cands):
         raise AssertionError(
             f"{kernel}/{backend} space must contain the default {dflt}")
     return cands
 
 
-def _clamped_default(kernel: str, backend: str,
-                     shape: Sequence[int]) -> dict[str, Any]:
+def _clamped_default(kernel: str, backend: str, shape: Sequence[int],
+                     dtype: str = "float32") -> dict[str, Any]:
     """Default params fitted to ``shape``: min-clamped (flash block_q=512
     on sq=256 runs as 256) and, for the divisibility-constrained kernels,
     halved to the nearest divisor (GEMM 384³ → 128 tiles) — the feasible
-    stand-in for the hardcoded default on shapes it cannot tile."""
+    stand-in for the hardcoded default on shapes it cannot tile.  The
+    fused row blocks are capped by VMEM (:func:`_row_cap`)."""
     p = default_params(kernel, backend)
     if backend != "pallas":
         return p
@@ -504,9 +520,12 @@ def _clamped_default(kernel: str, backend: str,
     elif kernel == "ssd_scan":
         s = shape[2]
         p["chunk"] = fit_block(p["chunk"], s)
+    elif kernel in ("fused_norm", "fused_swiglu"):
+        p["block_rows"] = min(p["block_rows"],
+                              _row_cap(kernel, shape[1], dtype))
     return p
 
 
 def is_default(kernel: str, backend: str, shape: Sequence[int],
-               params: dict[str, Any]) -> bool:
-    return params == _clamped_default(kernel, backend, shape)
+               params: dict[str, Any], dtype: str = "float32") -> bool:
+    return params == _clamped_default(kernel, backend, shape, dtype)

@@ -51,9 +51,11 @@ _SPAWN = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((8,), ("pod",))
 
     # --- compressed psum vs exact psum (distributed.compression) -----------
     from repro.distributed.compression import psum_compressed

@@ -90,8 +90,13 @@ class TestFlopModel:
         comp = _compile(f, jax.ShapeDtypeStruct((B, HW, HW, Cin), jnp.float32),
                         jax.ShapeDtypeStruct((K, K, Cin, Cout), jnp.float32))
         an = analyze_compiled(comp)
-        expect = 2 * B * HW * HW * Cout * K * K * Cin
+        # SAME padding: a border output position loses the taps that
+        # land on padding, so each spatial dim has HW*K - 2 valid taps
+        taps = HW * K - 2
+        expect = 2 * B * Cout * Cin * taps * taps
         assert an.total_flops == pytest.approx(expect, rel=0.05)
+        assert an.total_flops == pytest.approx(_cost(comp)["flops"],
+                                               rel=0.05)
 
     def test_dtype_classes(self):
         f = lambda a, b: (a @ b).astype(jnp.float32)

@@ -30,8 +30,8 @@ def _check_many(prop, cases):
         ints = [st.integers(lo, hi) for lo, hi in cases]
 
         @settings(max_examples=100, deadline=None)
-        @given(*ints)
-        def inner(*args):
+        @given(st.tuples(*ints))
+        def inner(args):
             prop(*args)
 
         inner()

@@ -513,6 +513,26 @@ class TestObsCli:
         out = capsys.readouterr().out
         assert "[launch_overhead]" in out and "evidence:" in out
 
+    def test_read_only_commands_leave_the_device_alone(self, tmp_path,
+                                                       monkeypatch):
+        """trend / advise / merge only read the stores: they neither ask
+        JAX for a device nor turn the compile cache on, either of which
+        would claim an accelerator from the process measuring on it."""
+        from repro import cli
+        from repro.core import machine
+
+        def claim(*a, **k):
+            raise AssertionError("a read-only command asked for the device")
+
+        a, b = str(tmp_path / "a"), str(tmp_path / "b")
+        self._seed(a, [_record("r1")])
+        self._seed(b, [_record("r2", host="hostB")])
+        monkeypatch.setattr(machine, "machine_for_device", claim)
+        monkeypatch.setattr(cli, "enable_compile_cache", claim)
+        assert cli.main(["--workspace", a, "trend"]) == 0
+        assert cli.main(["--workspace", a, "advise"]) == 0
+        assert cli.main(["--workspace", a, "merge", b]) == 0
+
     def test_merge_cli_and_idempotency(self, tmp_path, capsys):
         from repro.cli import main
         a, b = str(tmp_path / "a"), str(tmp_path / "b")

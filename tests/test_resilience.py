@@ -322,6 +322,24 @@ class TestSupervisedPool:
         with SupervisedPool(_wd_sleep, 1) as pool:
             assert pool.run([("z", (0.0,))])["z"].value == "woke"
 
+    def test_refuses_when_this_process_holds_the_chip(self, monkeypatch):
+        from repro.resilience import watchdog
+        assert not watchdog.holds_accelerator()      # the CPU test backend
+        monkeypatch.setattr(watchdog, "holds_accelerator", lambda: True)
+        with pytest.raises(RuntimeError, match="holds the accelerator"):
+            SupervisedPool(_wd_double, 1)
+
+    def test_slow_start_does_not_count_against_the_deadline(self):
+        """A worker's start (a fresh interpreter, its imports, the
+        initializer) may outlast a short task deadline.  Reaped at the
+        deadline, it would be respawned into the same slow start and the
+        task would end as a crash without ever running."""
+        with SupervisedPool(_wd_double, 1, deadline_s=0.5, init=_wd_sleep,
+                            initargs=(2.0,)) as pool:
+            out = pool.run([("a", (3,))])
+        assert out["a"].kind == "ok" and out["a"].value == 6
+        assert pool.replacements == 0
+
     def test_deadline_kills_hung_worker(self):
         t0 = time.monotonic()
         with SupervisedPool(_wd_sleep, 1, deadline_s=0.5) as pool:
@@ -545,6 +563,27 @@ class TestSweepResume:
                           journal_path=lost)
         assert again.n_resumed == 1              # store scan alone suffices
         assert len(TraceStore(store).records()) == 1
+
+
+def test_sweep_runs_inline_in_the_process_holding_the_chip(monkeypatch,
+                                                          tmp_path):
+    """Asked for workers by a process on an accelerator, a sweep runs every
+    point in that process: a worker could never reach the chip."""
+    from repro.sweep import engine
+    from repro.sweep.spec import SweepSpec
+
+    def no_pool(*a, **k):
+        raise AssertionError("a worker pool was started")
+
+    monkeypatch.setattr(engine, "holds_accelerator", lambda: True)
+    monkeypatch.setattr(engine, "SupervisedPool", no_pool)
+    spec = SweepSpec(name="chip-inline", configs=("minitron-4b",),
+                     seqs=(16,), batches=(2,), amps=("O1",),
+                     meshes=((1, 1),), machine="cpu-host", measure=False,
+                     smoke=True)
+    res = engine.run_sweep(spec, store_path=str(tmp_path / "sweep.jsonl"),
+                           workers=2, cache_dir=str(tmp_path / "cache"))
+    assert res.n_ok == 1
 
 
 class TestFailureSummary:

@@ -99,3 +99,20 @@ class TestMachineSpec:
         assert get_machine("tpu-v5e").name == "tpu-v5e"
         with pytest.raises(KeyError):
             get_machine("nope")
+
+    @pytest.mark.parametrize("kind,name", [("TPU v5 lite", "tpu-v5e"),
+                                           ("cpu", "cpu-host")])
+    def test_machine_for_device_kind(self, kind, name):
+        from types import SimpleNamespace
+        from repro.core.machine import machine_for_device
+        assert machine_for_device(
+            SimpleNamespace(device_kind=kind)).name == name
+
+    def test_machine_for_this_process_and_unknown_kinds(self, tmp_path):
+        from types import SimpleNamespace
+        from repro.core.machine import machine_for_device
+        from repro.session import Session
+        assert machine_for_device().name == "cpu-host"   # the test backend
+        assert Session(workspace=str(tmp_path)).machine.name == "cpu-host"
+        with pytest.raises(KeyError, match="TPU v9"):
+            machine_for_device(SimpleNamespace(device_kind="TPU v9"))

@@ -119,11 +119,11 @@ _SPAWN = textwrap.dedent("""
     from repro.configs.registry import get_smoke
     from repro.core import analyze_compiled, get_machine, roofline_terms
     from repro.distributed import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models import api as M
-    from repro.core.compat import mesh_context
     from repro.train import step as TS
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_smoke("granite-8b")
     run = RunConfig(amp="O1")
     model = M.build(cfg)
@@ -143,7 +143,7 @@ _SPAWN = textwrap.dedent("""
         batch_abs, shd.shard_batch_dim(batch_abs, mesh, run))
 
     step = TS.make_train_step(model, run)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, donate_argnums=0).lower(
             state_specs, batch_specs).compile()
     an = analyze_compiled(compiled, devices_per_pod=8)
@@ -154,10 +154,10 @@ _SPAWN = textwrap.dedent("""
     import tempfile, numpy as np
     from repro.checkpoint import checkpointer as ckpt
     from repro.train.step import init_state
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         state = init_state(model, run, jax.random.PRNGKey(0))
         state = jax.device_put(state, state_sh)
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = make_mesh((4, 2), ("data", "model"))
     pshard2 = shd.param_shardings(model.spec, mesh2, run)
     oshard2 = shd.opt_state_shardings(state_abs.opt, pshard2, mesh2)
     rep2 = shd.replicated(mesh2)

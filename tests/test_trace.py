@@ -206,6 +206,53 @@ class TestStore:
             assert key in r.phases["fwd"], key
         assert r.phases["fwd"]["kernels"], "top kernels persisted"
 
+    def test_host_fingerprint_names_the_device(self):
+        import jax
+        from repro.trace.store import host_fingerprint
+        fp = host_fingerprint()
+        assert fp["device_kind"] == jax.devices()[0].device_kind
+        assert fp["device_count"] == str(jax.device_count())
+
+    @pytest.mark.parametrize("layout", ["loose", "packed", "detached",
+                                        "worktree"])
+    def test_git_sha_read_from_dot_git(self, tmp_path, layout):
+        from repro.trace.store import git_sha
+        sha = "0123456789abcdef0123456789abcdef01234567"
+        git = tmp_path / "main" / ".git"
+        (git / "refs" / "heads").mkdir(parents=True)
+        if layout == "detached":
+            (git / "HEAD").write_text(sha + "\n")
+        else:
+            (git / "HEAD").write_text("ref: refs/heads/main\n")
+        if layout in ("loose", "worktree"):
+            (git / "refs" / "heads" / "main").write_text(sha + "\n")
+        elif layout == "packed":
+            (git / "packed-refs").write_text(
+                f"# pack-refs with: peeled\n{sha} refs/heads/main\n")
+        checkout = tmp_path / "main"
+        if layout == "worktree":
+            # the worktree's own HEAD names a branch of the main repo
+            (git / "HEAD").write_text("ref: refs/heads/other\n")
+            wt_git = git / "worktrees" / "wt"
+            wt_git.mkdir(parents=True)
+            (wt_git / "HEAD").write_text("ref: refs/heads/main\n")
+            (wt_git / "commondir").write_text("../..\n")
+            checkout = tmp_path / "wt"
+            checkout.mkdir()
+            (checkout / ".git").write_text(f"gitdir: {wt_git}\n")
+        assert git_sha(str(checkout)) == sha
+
+    def test_git_sha_never_looks_above_the_checkout(self, tmp_path):
+        from repro.trace.store import git_sha
+        assert git_sha(str(tmp_path)) == "unknown"
+        git = tmp_path / ".git"
+        git.mkdir()
+        (git / "HEAD").write_text("0123456789abcdef\n")
+        # an exported tree inside a repository has no history of its own
+        export = tmp_path / "archive" / "repo"
+        export.mkdir(parents=True)
+        assert git_sha(str(export)) == "unknown"
+
     def test_append_only_and_filtering(self, tmp_path):
         store = TraceStore(str(tmp_path / "t.jsonl"))
         for cfg in ("a", "b", "a"):

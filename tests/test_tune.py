@@ -165,6 +165,25 @@ class TestSearch:
         else:
             assert dflt == {"chunk": 64}
 
+    @pytest.mark.parametrize("kernel,shape", [
+        ("fused_norm", (8192, 3072)),
+        ("fused_swiglu", (8192, 9216)),
+    ])
+    def test_row_blocks_capped_by_vmem(self, kernel, shape):
+        # the kernel caps its row block by VMEM; the space names only
+        # blocks that run as named, the default among them
+        from repro.kernels.fused.common import vmem_rows
+        cap = vmem_rows(shape[1], ["bfloat16"] * (
+            4 if kernel == "fused_norm" else 3))
+        cands = sp.candidates(kernel, shape, "bfloat16")
+        blocks = [c.dict["block_rows"] for c in cands]
+        assert max(blocks) == cap < sp.default_params(kernel)["block_rows"]
+        assert len(set(blocks)) == len(blocks)
+        assert sum(sp.is_default(kernel, "pallas", shape, c.dict, "bfloat16")
+                   for c in cands) == 1
+        assert sp.is_default(kernel, "pallas", shape,
+                             {"block_rows": cap}, "bfloat16")
+
     def test_fit_block(self):
         assert sp.fit_block(256, 384) == 128
         assert sp.fit_block(512, 768) == 256
