@@ -183,47 +183,55 @@ def deepcam_forward(params: Params, images: jax.Array, run: RunConfig,
     H, W = x.shape[1], x.shape[2]
 
     up = not fused
-    x = jax.nn.relu(_bn(_conv(x, params["stem"], 2, 1, cd),
-                        params["stem_n"], upcast=up))
+    # part scopes: each op's HLO ``op_name`` names the part it belongs to
+    with jax.named_scope("deepcam.stem"):
+        x = jax.nn.relu(_bn(_conv(x, params["stem"], 2, 1, cd),
+                            params["stem_n"], upcast=up))
     skip = None
     mid = None
     for si, (stage_p, (_blocks, _c, stride, dil)) in enumerate(
             zip(params["stages"], _STAGES)):
-        for bi, bp in enumerate(stage_p):
-            x = _bottleneck(x, bp, stride if bi == 0 else 1, dil, cd, fused)
+        with jax.named_scope(f"deepcam.stage{si + 1}"):
+            for bi, bp in enumerate(stage_p):
+                x = _bottleneck(x, bp, stride if bi == 0 else 1, dil, cd,
+                                fused)
         if si == 0:
             skip = x
         if si == 1:
             mid = x
 
-    # ASPP
-    hw = (x.shape[1], x.shape[2])
-    branches = [jax.nn.relu(_conv(x, params["aspp"][f"r{r}"], 1,
-                                  1 if r == 1 else r, cd))
-                for r in _ASPP_RATES]
-    pooled = jnp.mean(x, axis=(1, 2), keepdims=True)
-    pooled = jax.nn.relu(_conv(pooled, params["aspp"]["pool"], cd=cd))
-    branches.append(jnp.broadcast_to(
-        pooled, (x.shape[0], *hw, pooled.shape[-1])))
-    x = jnp.concatenate(branches, axis=-1)
-    x = jax.nn.relu(_bn(_conv(x, params["aspp"]["proj"], cd=cd),
-                        params["aspp"]["proj_n"], upcast=up))
+    with jax.named_scope("deepcam.aspp"):
+        hw = (x.shape[1], x.shape[2])
+        branches = [jax.nn.relu(_conv(x, params["aspp"][f"r{r}"], 1,
+                                      1 if r == 1 else r, cd))
+                    for r in _ASPP_RATES]
+        pooled = jnp.mean(x, axis=(1, 2), keepdims=True)
+        pooled = jax.nn.relu(_conv(pooled, params["aspp"]["pool"], cd=cd))
+        branches.append(jnp.broadcast_to(
+            pooled, (x.shape[0], *hw, pooled.shape[-1])))
+        x = jnp.concatenate(branches, axis=-1)
+        x = jax.nn.relu(_bn(_conv(x, params["aspp"]["proj"], cd=cd),
+                            params["aspp"]["proj_n"], upcast=up))
 
     # decoder: upsample to skip resolution, two skip connections
     dp = params["dec"]
-    x = _resize(x, (skip.shape[1], skip.shape[2]))
-    sk = _conv(skip, dp["skip_proj"], cd=cd)
-    x = jnp.concatenate([x, sk], axis=-1)
-    x = jax.nn.relu(_bn(_conv(x, dp["d1"], cd=cd), dp["d1n"], upcast=up))
-    x = jax.nn.relu(_bn(_conv(x, dp["d2"], cd=cd), dp["d2n"], upcast=up))
-    # second skip: encoder-middle features, projected + upsampled (paper §III-B)
-    mk = _resize(_conv(mid, dp["mid_proj"], cd=cd), (x.shape[1], x.shape[2]))
-    x = jnp.concatenate([x, mk], axis=-1)
-    x = jax.nn.relu(_bn(_conv(x, dp["d3"], cd=cd), dp["d3n"], upcast=up))
-    x = _resize(x, (H, W))
-    x = jax.nn.relu(_bn(_conv(x, dp["d4"], cd=cd), dp["d4n"], upcast=up))
-    x = jax.nn.relu(_bn(_conv(x, dp["d5"], cd=cd), dp["d5n"], upcast=up))
-    return _conv(x, dp["head"], cd=cd).astype(jnp.float32)
+    with jax.named_scope("deepcam.decoder"):
+        x = _resize(x, (skip.shape[1], skip.shape[2]))
+        sk = _conv(skip, dp["skip_proj"], cd=cd)
+        x = jnp.concatenate([x, sk], axis=-1)
+        x = jax.nn.relu(_bn(_conv(x, dp["d1"], cd=cd), dp["d1n"], upcast=up))
+        x = jax.nn.relu(_bn(_conv(x, dp["d2"], cd=cd), dp["d2n"], upcast=up))
+        # second skip: encoder-middle features, projected + upsampled
+        # (paper §III-B)
+        mk = _resize(_conv(mid, dp["mid_proj"], cd=cd),
+                     (x.shape[1], x.shape[2]))
+        x = jnp.concatenate([x, mk], axis=-1)
+        x = jax.nn.relu(_bn(_conv(x, dp["d3"], cd=cd), dp["d3n"], upcast=up))
+        x = _resize(x, (H, W))
+        x = jax.nn.relu(_bn(_conv(x, dp["d4"], cd=cd), dp["d4n"], upcast=up))
+        x = jax.nn.relu(_bn(_conv(x, dp["d5"], cd=cd), dp["d5n"], upcast=up))
+    with jax.named_scope("deepcam.head"):
+        return _conv(x, dp["head"], cd=cd).astype(jnp.float32)
 
 
 def deepcam_loss(params: Params, images: jax.Array, labels: jax.Array,

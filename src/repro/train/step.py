@@ -74,7 +74,8 @@ def make_train_step(model: Model, run: RunConfig, lr: float = 3e-4
 
     grad_fn = jax.value_and_grad(loss_of, has_aux=True)
 
-    def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
+    def _grads(state: TrainState, batch: dict) -> tuple[Any, dict]:
+        """The batch's gradients and metrics, over its microbatches."""
         m = run.microbatches
         if m > 1:
             mbs = _split_microbatches(batch, m)
@@ -105,25 +106,37 @@ def make_train_step(model: Model, run: RunConfig, lr: float = 3e-4
         else:
             (_, metrics), grads = grad_fn(state.params, batch,
                                           state.loss_scale)
+        return grads, metrics
 
-        if use_scaling:
-            grads, new_scale, finite = amp.unscale_and_update(
-                grads, state.loss_scale)
-        else:
-            new_scale, finite = state.loss_scale, jnp.array(True)
+    def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
+        # phase scopes: the forward's ops read ``train.grad/jvp(...)`` in
+        # their HLO ``op_name``, the backward's ``transpose(...)`` as well
+        with jax.named_scope("train.grad"):
+            grads, metrics = _grads(state, batch)
 
-        new_params, new_opt = optim.optimizer_update(
-            grads, state.opt, state.params, run, lr=lr)
-        # overflow → skip the update (keep old params/opt), shrink the scale
-        new_params = jax.tree.map(
-            lambda n, o: jnp.where(finite, n, o), new_params, state.params)
-        new_opt = jax.tree.map(
-            lambda n, o: jnp.where(finite, n, o), new_opt, state.opt)
-        metrics = dict(metrics)
-        metrics["grads_finite"] = finite.astype(jnp.float32)
-        metrics["grad_norm"] = jnp.sqrt(sum(
-            jnp.sum(g.astype(jnp.float32) ** 2)
-            for g in jax.tree.leaves(grads)))
+        with jax.named_scope("train.optimizer"):
+            if use_scaling:
+                grads, new_scale, finite = amp.unscale_and_update(
+                    grads, state.loss_scale)
+            else:
+                new_scale, finite = state.loss_scale, jnp.array(True)
+
+            new_params, new_opt = optim.optimizer_update(
+                grads, state.opt, state.params, run, lr=lr)
+            # overflow → skip the update (keep old params/opt), shrink the
+            # scale
+            new_params = jax.tree.map(
+                lambda n, o: jnp.where(finite, n, o), new_params,
+                state.params)
+            new_opt = jax.tree.map(
+                lambda n, o: jnp.where(finite, n, o), new_opt, state.opt)
+
+        with jax.named_scope("train.metrics"):
+            metrics = dict(metrics)
+            metrics["grads_finite"] = finite.astype(jnp.float32)
+            metrics["grad_norm"] = jnp.sqrt(sum(
+                jnp.sum(g.astype(jnp.float32) ** 2)
+                for g in jax.tree.leaves(grads)))
         return TrainState(new_params, new_opt, new_scale,
                           state.step + 1), metrics
 

@@ -39,6 +39,7 @@ from repro.checkpoint import checkpointer as ckpt
 from repro.configs.base import RunConfig
 from repro.models.api import Model
 from repro.resilience import faults
+from repro.trace import spans
 from repro.train.step import TrainState, init_state, make_train_step
 
 
@@ -149,7 +150,8 @@ class Trainer:
             try:
                 plan.maybe_crash("crash_step", target=i)
                 plan.maybe_raise("step_fault", target=i)
-                return self.step_fn(self.state, batch)
+                with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                    return self.step_fn(self.state, batch)
             except faults.TransientFault as e:
                 if attempt + 1 >= attempts:
                     raise
@@ -164,6 +166,7 @@ class Trainer:
     def fit(self, n_steps: int, log_every: int = 10,
             log: Callable[[str], None] = print) -> TrainReport:
         self._install_sigterm()
+        annotate = jax.profiler.TraceAnnotation
         ewma = None
         start_step = int(self.state.step)
         ctx = self.mesh if self.mesh is not None else _null_ctx()
@@ -173,39 +176,53 @@ class Trainer:
                 if self._stop:
                     log(f"[trainer] SIGTERM at step {i}; checkpointing")
                     break
-                batch = self._put_batch(self.make_batch(i))
-                t0 = time.perf_counter()
-                self.state, metrics = self._step_resilient(i, batch, log,
-                                                           plan)
-                jax.block_until_ready(metrics["loss"])
-                dt = time.perf_counter() - t0
+                # each part of the step in its host span (repro.trace.spans)
+                with jax.profiler.StepTraceAnnotation(spans.STEP,
+                                                      step_num=i):
+                    with annotate(spans.BATCH):
+                        host_batch = self.make_batch(i)
+                    with annotate(spans.PUT):
+                        batch = self._put_batch(host_batch)
+                    t0 = time.perf_counter()
+                    self.state, metrics = self._step_resilient(i, batch, log,
+                                                               plan)
+                    with annotate(spans.WAIT):
+                        jax.block_until_ready(metrics["loss"])
+                    dt = time.perf_counter() - t0
 
-                # straggler detection (per-step heartbeat timing)
-                if ewma is not None and dt > self.straggler_factor * ewma:
-                    self.report.stragglers.append((i, dt, ewma))
-                ewma = dt if ewma is None else 0.9 * ewma + 0.1 * dt
+                    with annotate(spans.READBACK):
+                        # straggler detection (per-step heartbeat timing)
+                        if (ewma is not None
+                                and dt > self.straggler_factor * ewma):
+                            self.report.stragglers.append((i, dt, ewma))
+                        ewma = dt if ewma is None else 0.9 * ewma + 0.1 * dt
 
-                loss = float(metrics["loss"])
-                self.report.steps += 1
-                self.report.losses.append(loss)
-                self.report.grad_norms.append(float(metrics["grad_norm"]))
-                self.report.step_times.append(dt)
-                if log_every and i % log_every == 0:
-                    log(f"[trainer] step {i:5d} loss {loss:.4f} "
-                        f"({dt*1e3:.1f} ms, grad_norm "
-                        f"{self.report.grad_norms[-1]:.3f})")
-                    if not self._async_ckpt.healthy():
-                        log(f"[trainer] checkpoint writer failed; "
-                            f"surfacing at step {i}")
-                        self._async_ckpt.wait()    # raises the stored error
-                if (self.ckpt_dir is not None and self.ckpt_every
-                        and (i + 1) % self.ckpt_every == 0):
-                    self._async_ckpt.save(self.ckpt_dir, i + 1, self.state,
-                                          {"step": i + 1})
+                        loss = float(metrics["loss"])
+                        self.report.steps += 1
+                        self.report.losses.append(loss)
+                        self.report.grad_norms.append(
+                            float(metrics["grad_norm"]))
+                        self.report.step_times.append(dt)
+                        if log_every and i % log_every == 0:
+                            log(f"[trainer] step {i:5d} loss {loss:.4f} "
+                                f"({dt*1e3:.1f} ms, grad_norm "
+                                f"{self.report.grad_norms[-1]:.3f})")
+                            if not self._async_ckpt.healthy():
+                                log(f"[trainer] checkpoint writer failed; "
+                                    f"surfacing at step {i}")
+                                # raises the stored error
+                                self._async_ckpt.wait()
+                    if (self.ckpt_dir is not None and self.ckpt_every
+                            and (i + 1) % self.ckpt_every == 0):
+                        with annotate(spans.CHECKPOINT):
+                            self._async_ckpt.save(self.ckpt_dir, i + 1,
+                                                  self.state, {"step": i + 1})
         if self.ckpt_dir is not None:
-            self._async_ckpt.save(self.ckpt_dir, int(self.state.step),
-                                  self.state, {"step": int(self.state.step)})
-            self._async_ckpt.wait()
+            with annotate(spans.CHECKPOINT):
+                self._async_ckpt.save(self.ckpt_dir, int(self.state.step),
+                                      self.state,
+                                      {"step": int(self.state.step)})
+                self._async_ckpt.wait()
         return self.report
 
 
