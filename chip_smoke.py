@@ -278,7 +278,8 @@ def phase_train(sz: Sizes) -> None:
     image = trainer.make_batch(0)["images"].shape
     say("train", f"deepcam images {image} | losses {rep.losses} | "
                  f"grad norms {rep.grad_norms}")
-    say("train", "host wall per step (s, first includes compile) "
+    say("train", "time between step completions (s, first is dispatch to "
+                 "ready and includes compile) "
                  f"{[round(t, 3) for t in rep.step_times]}")
     say("train", f"max |param change| {moved:.3e} | peak_bytes_in_use "
                  f"{peak_bytes()}")

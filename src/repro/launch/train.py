@@ -90,8 +90,9 @@ def main(argv=None) -> int:
                             ckpt_every=args.ckpt_every)
     report = trainer.fit(args.steps)
     print(f"[train] {report.steps} steps, final loss "
-          f"{report.losses[-1]:.4f}, mean step "
+          f"{report.losses[-1]:.4f}, mean time between step completions "
           f"{1e3 * sum(report.step_times[1:]) / max(len(report.step_times) - 1, 1):.1f} ms, "
+          f"overlapped {report.overlapped}, "
           f"stragglers {len(report.stragglers)}, "
           f"resumed_from={report.resumed_from}")
     return 0

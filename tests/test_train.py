@@ -218,3 +218,143 @@ class TestTrainerFaultTolerance:
         assert len(rep.stragglers) >= 1
         step_idx, dt, ewma = rep.stragglers[0]
         assert dt > 0 and ewma > 0
+
+
+class TestTrainerOverlap:
+    """``fit`` dispatches step i+1 before it reads step i's metrics; the
+    last step of a call stays in flight until something needs it."""
+
+    N = 5
+
+    @pytest.fixture(scope="class")
+    def base(self, setup):
+        """One compiled step shared by every trainer of the class, and a
+        plain synchronous loop over it: the losses, grad norms and final
+        parameters every overlapped run must equal bit for bit."""
+        from repro.train.trainer import Trainer
+        cfg, model, _ = setup
+        stream = lambda step: synthetic_batch(cfg, SHAPE, 8, seed=step)
+        t = Trainer(model, RunConfig(amp="O1"), stream, lr=1e-3)
+        state, losses, norms = t.state, [], []
+        for i in range(self.N):
+            state, m = t.step_fn(state, t._put_batch(t.make_batch(i)))
+            losses.append(float(m["loss"]))
+            norms.append(float(m["grad_norm"]))
+        return t.step_fn, stream, (losses, norms, state.params)
+
+    def _trainer(self, setup, base, **kw):
+        from repro.train.trainer import Trainer
+        _, model, _ = setup
+        step_fn, stream, _ = base
+        t = Trainer(model, RunConfig(amp="O1"), kw.pop("stream", stream),
+                    lr=1e-3, **kw)
+        t.step_fn = step_fn
+        return t
+
+    @pytest.mark.parametrize("calls", ["one-call", "one-step-per-call"])
+    def test_matches_a_synchronous_loop_bit_for_bit(self, setup, base,
+                                                    calls):
+        losses, norms, params = base[2]
+        t = self._trainer(setup, base)
+        if calls == "one-call":
+            t.fit(self.N, log_every=0)
+        else:
+            for k in range(self.N):
+                t.fit(k + 1, log_every=0)
+        rep = t.report
+        assert [x.hex() for x in rep.losses] == [x.hex() for x in losses]
+        assert [x.hex() for x in rep.grad_norms] == [x.hex() for x in norms]
+        for a, b in zip(jax.tree.leaves(t.state.params),
+                        jax.tree.leaves(params)):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        assert int(t.state.step) == self.N
+
+    @pytest.mark.parametrize("resolve", ["report-read", "field-read",
+                                         "state-assigned",
+                                         "report-assigned"])
+    def test_one_step_stays_in_flight_until_needed(self, setup, base,
+                                                   resolve):
+        t = self._trainer(setup, base)
+        rep = t.fit(3, log_every=0)
+        assert t._pending is not None and t._pending.step == 2
+        t.state.params                       # reading the state: no wait
+        assert t._pending is not None
+        assert len(object.__getattribute__(rep, "losses")) == 2
+        if resolve == "report-read":
+            t.report
+        elif resolve == "field-read":
+            rep.steps
+        elif resolve == "state-assigned":
+            t.state = t.state
+        else:
+            t.report = type(rep)()
+        assert t._pending is None
+        assert rep.steps == 3
+        assert len(rep.losses) == len(rep.grad_norms) == \
+            len(rep.step_times) == 3
+        assert all(dt > 0 for dt in rep.step_times)
+
+    def test_overlapped_counts_each_step_after_the_first(self, setup, base):
+        one = self._trainer(setup, base)
+        assert one.fit(4, log_every=0).overlapped == 3
+        each = self._trainer(setup, base)
+        for k in range(4):
+            each.fit(k + 1, log_every=0)
+        assert each.report.overlapped == 3
+        assert each.report.steps == 4
+
+    def test_next_step_is_dispatched_before_a_step_is_read(self, setup,
+                                                           base):
+        events = []
+
+        class Probe:
+            def __init__(self, i):
+                self.i = i
+
+            def block_until_ready(self):
+                events.append(("wait", self.i))
+                return self
+
+            def __float__(self):
+                events.append(("read", self.i))
+                return 1.0
+
+        def fake_step(state, batch):
+            i = sum(kind == "dispatch" for kind, _ in events)
+            events.append(("dispatch", i))
+            return state, {"loss": Probe(i), "grad_norm": Probe(i)}
+
+        t = self._trainer(setup, base)
+        t.step_fn = fake_step
+        t.fit(4, log_every=0)
+        assert ("read", 3) not in events     # the last is still in flight
+        assert t.report.steps == 4
+        for i in range(3):
+            nxt = events.index(("dispatch", i + 1))
+            assert nxt < events.index(("wait", i))
+            assert nxt < events.index(("read", i))
+
+    def test_sigterm_checkpoints_the_step_in_flight(self, setup, base):
+        from repro.checkpoint import checkpointer as ckpt
+        losses = base[2][0]
+        stream = base[1]
+        with tempfile.TemporaryDirectory() as d:
+            t = None
+
+            def preempted(i):
+                if i == 2:
+                    t._stop = True           # what the SIGTERM handler does
+                return stream(i)
+
+            t = self._trainer(setup, base, stream=preempted, ckpt_dir=d,
+                              ckpt_every=0)
+            rep = t.fit(self.N, log_every=0, log=lambda *_: None)
+            # step 2 was in flight at the SIGTERM: resolved, then saved
+            assert t._pending is None
+            assert rep.steps == 3
+            assert ckpt.available_steps(d) == [3]
+            t2 = self._trainer(setup, base, ckpt_dir=d, ckpt_every=0)
+            assert t2.report.resumed_from == 3
+            rep2 = t2.fit(self.N, log_every=0, log=lambda *_: None)
+            assert [x.hex() for x in rep.losses + rep2.losses] == \
+                [x.hex() for x in losses]
