@@ -284,6 +284,20 @@ def all_within(checks: dict[str, tuple[float, float]]) -> bool:
 # what a runner is given and gives back
 # --------------------------------------------------------------------------
 
+def check_layout(mine, theirs) -> None:
+    """The reference's parameter tree (``mine``) has the program's
+    structure, shapes and dtypes."""
+    import jax
+    if jax.tree.structure(mine) != jax.tree.structure(theirs):
+        raise BenchError("the program's parameter tree differs from the "
+                         "reference's")
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
+        if a.shape != b.shape or a.dtype != b.dtype:
+            raise BenchError(f"parameter {a.shape} {a.dtype} of the "
+                             f"reference against {b.shape} {b.dtype} of the "
+                             "program")
+
+
 def seed_key(seed: int):
     """A JAX key for any non-negative whole number, however large."""
     import jax
@@ -315,6 +329,7 @@ class Outcome:
     checks: dict[str, tuple[float, float]]
     memory_peak_bytes: int
     reading: dict                       # what the per-layer readers read
+    extra: dict = dataclasses.field(default_factory=dict)  # result keys
 
 
 class Reading:

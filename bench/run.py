@@ -43,6 +43,9 @@ def per_layer(bm: dict, cell: dict, ctx: harness.Ctx,
         peaks=harness.peaks_for(ctx.devices[0].device_kind),
         window_host_s=ctx.tracer.stopped - ctx.tracer.started,
         **out.reading)
+    describe = getattr(harness.runner_of(ctx.cfg), "describe", None)
+    if describe is not None:
+        harness.say(describe(reading))
     metrics = {}
     for m in harness.cell_metrics(bm, cell, "per_layer"):
         value = harness.metric_reader(m["name"]).read(reading)
@@ -89,6 +92,7 @@ def main(argv=None) -> int:
                             "unit": m["unit"]}
                 for m in harness.cell_metrics(bm, cell, "end_to_end")},
                 device=device)
+        result.update(out.extra)
     except harness.BenchError as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2

@@ -47,18 +47,6 @@ def _norms_fn():
     return norms, diff_norms
 
 
-def _check_layout(mine, theirs) -> None:
-    import jax
-    if jax.tree.structure(mine) != jax.tree.structure(theirs):
-        raise harness.BenchError("the program's parameter tree differs from "
-                                 "the reference's")
-    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
-        if a.shape != b.shape or a.dtype != b.dtype:
-            raise harness.BenchError(
-                f"parameter {a.shape} {a.dtype} of the reference against "
-                f"{b.shape} {b.dtype} of the program")
-
-
 # --------------------------------------------------------------------------
 # the program
 # --------------------------------------------------------------------------
@@ -140,7 +128,7 @@ class Program:
             raise harness.BenchError("the pool must hold a batch for each "
                                      "set-up step, so that their rows differ")
         params0 = self.init(harness.seed_key(seed))
-        _check_layout(params0, tr.state.params)
+        harness.check_layout(params0, tr.state.params)
         zeros = jax.tree.map(lambda x: x * 0, (tr.state.opt, tr.state.step))
         tr.state = jax.device_put(
             tr.state._replace(params=params0, opt=zeros[0], step=zeros[1]),

@@ -54,4 +54,6 @@ def test_a_mix_names_its_kind(tmp_path):
 def test_every_mix_in_the_benchmark_loads():
     bm = benchkit.harness.load_benchmark()
     for cell in bm["workloads"]:
-        assert T.load(cell["traffic"])["kind"] == "train"
+        _cell, entry = benchkit.harness.find_cell(bm, cell["name"])
+        runner = benchkit.harness.load_config(entry)["runner"]
+        assert T.load(cell["traffic"])["kind"] == runner
